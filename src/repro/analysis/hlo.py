@@ -5,7 +5,7 @@ This is the measurement layer the declarative rules in
 :mod:`repro.analysis.rules` are built on: text parsing of compiled HLO
 (collective ops — including their *async* lowered forms — and dtype-sized
 result shapes) and structural walks of ClosedJaxprs (primitive census with
-recursion into ``while``/``scan``/``pjit``/pallas sub-jaxprs, with rank
+recursion into ``while``/``scan``/``jit``/pallas sub-jaxprs, with rank
 filtering and per-equation evidence). It subsumes the former
 ``repro.launch.hlo_analysis`` module, which survives as a thin re-export
 shim for external callers; everything in-repo goes through
@@ -97,7 +97,7 @@ class EqnSite:
     """One matched equation inside a (possibly nested) jaxpr."""
     primitive: str
     rank: int                      # max output rank
-    path: str                      # e.g. "while/body/pjit"
+    path: str                      # e.g. "while/body/jit"
     eqn: str = field(repr=False, default="")   # pretty-printed, truncated
     shape: tuple = ()              # shape of the max-rank output
 
@@ -109,7 +109,7 @@ class EqnSite:
 def find_jaxpr_primitives(closed_jaxpr, names, min_rank: int = 0
                           ) -> list[EqnSite]:
     """Every equation matching ``names`` (and the rank filter) in a
-    ClosedJaxpr, recursing into sub-jaxprs (scan/while/pjit/pallas
+    ClosedJaxpr, recursing into sub-jaxprs (scan/while/jit/pallas
     bodies). Returns :class:`EqnSite` evidence records — the structured
     counterpart of :func:`count_jaxpr_primitives`, used by contract
     Reports to *name* the offending equation instead of just counting."""
@@ -140,7 +140,7 @@ def find_jaxpr_primitives(closed_jaxpr, names, min_rank: int = 0
 
 def count_jaxpr_primitives(closed_jaxpr, names, min_rank: int = 0):
     """Count primitive occurrences (by name) in a ClosedJaxpr, recursing
-    into sub-jaxprs (scan/while/pjit/pallas bodies). ``min_rank`` filters to
+    into sub-jaxprs (scan/while/jit/pallas bodies). ``min_rank`` filters to
     equations whose first output has at least that many dims — e.g.
     ``count_jaxpr_primitives(jaxpr, ("scatter",), min_rank=3)`` counts
     pool-shaped scatters (the standalone window-writeback the fused kernel

@@ -85,6 +85,8 @@ class PredictiveSampler:
         # TPU fast path: the fused vocab-tiled Gumbel-argmax Pallas kernel
         # (kernels/spec_verify); interpret-mode on CPU, bit-identical.
         self.use_verify_kernel = use_verify_kernel
+        # params are an argument, not a closure: a closed-over model would be
+        # baked into the program as constants (gigabytes at published widths)
         self._round = jax.jit(self._round_impl)
 
     # ------------------------------------------------------------------
@@ -119,9 +121,9 @@ class PredictiveSampler:
                         jnp.asarray(seq_ids, jnp.int32))
 
     # ------------------------------------------------------------------
-    def _round_impl(self, state: GenState, target_len) -> GenState:
+    def _round_impl(self, params, state: GenState, target_len) -> GenState:
         state, _stats = verify_round(
-            self.params, self.cfg, self.eps_fn, state, target_len,
+            params, self.cfg, self.eps_fn, state, target_len,
             use_forecast_heads=self.use_forecast_heads,
             use_verify_kernel=self.use_verify_kernel)
         return state
@@ -139,7 +141,7 @@ class PredictiveSampler:
         state = self.init_state(jnp.asarray(prompts, jnp.int32), B,
                                 seq_ids=seq_ids)
         while bool(jnp.any(state.n < target)):
-            state = self._round(state, target)
+            state = self._round(self.params, state, target)
         stats = {
             "rounds": int(state.rounds),
             "per_seq_calls": jax.device_get(state.per_seq_calls),

@@ -50,7 +50,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     v = jnp.where(in_bounds, v, 0.0)
     s = (q @ k.T) * scale                                # (W, bk)
 
-    base = len_ref[0]                                    # valid cache length
+    base = len_ref[pl.program_id(0)]                     # valid cache length
     q_pos = base + jax.lax.broadcasted_iota(jnp.int32, (W, bk), 0)
     k_pos = jk * bk + jax.lax.broadcasted_iota(jnp.int32, (W, bk), 1)
     mask = (k_pos <= q_pos) & (k_pos < s_len)
@@ -58,19 +58,18 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         mask &= k_pos > (q_pos - window)
     s = jnp.where(mask, s, NEG)
 
-    m_prev, l_prev = m_ref[...], l_ref[...]
-    m_cur = jnp.max(s, axis=1)
+    m_prev, l_prev = m_ref[...], l_ref[...]                # (W, 1)
+    m_cur = jnp.max(s, axis=1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = alpha * l_prev + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + p @ v
+    l_ref[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + p @ v
     m_ref[...] = m_new
 
     @pl.when(jk == nk - 1)
     def _emit():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[...], 1e-30)[:, None]
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                     ).astype(o_ref.dtype)
 
 
@@ -86,24 +85,28 @@ def decode_attention_kernel(q, k, v, lengths, window: int = 0,
     S = k.shape[1]
     bk = min(block_k, S)
 
+    # lengths ride in SMEM via scalar prefetch; the online-softmax state is
+    # rank-2 ((W, 1) max/sum) — Mosaic refuses rank-1 SMEM and VMEM blocks
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(BH, -(-S // bk)),
+        in_specs=[
+            pl.BlockSpec((1, W, d), lambda b, j, ln: (b, 0, 0)),
+            pl.BlockSpec((1, bk, d), lambda b, j, ln: (b, j, 0)),
+            pl.BlockSpec((1, bk, d), lambda b, j, ln: (b, j, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, W, d), lambda b, j, ln: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((W, 1), jnp.float32),
+            pltpu.VMEM((W, 1), jnp.float32),
+            pltpu.VMEM((W, d), jnp.float32),
+        ],
+    )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, bk=bk, s_len=S,
                           scale=1.0 / d ** 0.5, window=window),
-        grid=(BH, -(-S // bk)),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, j: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, W, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, W, d), lambda b, j: (b, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, W, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((W,), jnp.float32),
-            pltpu.VMEM((W,), jnp.float32),
-            pltpu.VMEM((W, d), jnp.float32),
-        ],
         interpret=resolve_interpret(interpret),
     )(lengths.astype(jnp.int32), q, k, v)
     return out

@@ -11,13 +11,16 @@ window keys/values in their blocks. This kernel fuses that write into the
 kernel itself, so one pallas_call per layer both reads the pool and commits
 the window (DESIGN.md §11):
 
-grid = (B, KV, nb): per (sequence, kv-head), logical KV blocks stream
-sequentially. The per-sequence block table and valid lengths ride in SMEM via
-scalar prefetch, so the K/V BlockSpec index_map resolves ``table[b, j]``
-before each tile's DMA — the pool is read once, block-granular, and no dense
-view ever exists. Online-softmax state for all G*W rows (G grouped query
-heads x W window queries) lives in VMEM scratch, exactly like the dense
-``decode_attention`` kernel.
+grid = (B, nb): per sequence, logical KV blocks stream sequentially. Each
+pool tile is a whole block ``(1, bs, KV, d)`` across the kv heads — Mosaic
+requires a block's last two dims to be divisible by (8, 128) or equal to the
+array's, which a one-head ``(1, bs, 1, d)`` tile is not — and the kernel walks
+the kv heads in a static loop. The per-sequence block table and valid
+lengths ride in SMEM via scalar prefetch, so the K/V BlockSpec index_map
+resolves ``table[b, j]`` before each tile's DMA — the pool is read once,
+block-granular, and no dense view ever exists. Online-softmax state for all
+KV*G*W rows (G grouped query heads x W window queries per kv head) lives in
+VMEM scratch, exactly like the dense ``decode_attention`` kernel.
 
 Fused writeback (the epilogue):
 
@@ -34,12 +37,11 @@ Fused writeback (the epilogue):
   cheap sink dumps) are flushed, and every unvisited block keeps its
   contents through the aliasing. Interpret mode initializes aliased outputs
   from the input arrays, so CPU CI sees identical semantics.
-* Each (b, h) visits each logical block once, window blocks are
+* Each sequence visits each logical block once and window blocks are
   sequence-private (shared prefix blocks always sit strictly below the
-  window span) and different kv heads touch disjoint tile slices, so the
-  only physical block written by more than one grid step is the sink —
-  whose contents are garbage by design. That makes the in-place aliasing
-  race-free on TPU.
+  window span), so the only physical block written by more than one grid
+  step is the sink — whose contents are garbage by design. That makes the
+  in-place aliasing race-free on TPU.
 
 Masking handles the two paged-specific hazards:
 
@@ -73,15 +75,16 @@ from jax.experimental.pallas import tpu as pltpu
 NEG = -1.0e30
 
 
-def _merge_window(tile, new_rows, off, valid, W: int):
-    """Select window rows into a pool tile: slot t takes ``new_rows[off[t]]``
-    where ``0 <= off[t] < W`` (and ``valid``), else keeps ``tile[t]``.
-    Unrolled W-way select — bitwise equal to the reference scatter, and
-    lowers to plain vector selects on TPU (no dynamic gather)."""
-    shaped = off.reshape((off.shape[0],) + (1,) * (tile.ndim - 1))
+def _merge_window(tile, new_rows, first, valid, W: int):
+    """Select window rows into a pool tile ``(bs, ...)``: slot t takes
+    ``new_rows[first + t]`` where ``0 <= first + t < W`` (and ``valid``),
+    else keeps ``tile[t]``. Unrolled W-way select — bitwise equal to the
+    reference scatter, and lowers to plain vector selects on TPU (no dynamic
+    gather)."""
+    off = first + jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
     merged = tile
     for w in range(W):
-        take = (shaped == w) & valid
+        take = (off == w) & valid
         merged = jnp.where(take, new_rows[w][None], merged)
     return merged
 
@@ -95,8 +98,9 @@ def _paged_kernel(tbl_ref, len_ref, *refs, bs: int, scale: float,
         (q1_ref, k1_ref, v_ref, n1_ref, n2_ref,
          o_ref, ok1_ref, ok2_ref, m_ref, l_ref, acc_ref) = refs
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
+    KV = q1_ref.shape[1]
 
     @pl.when(j == 0)
     def _init():
@@ -107,29 +111,21 @@ def _paged_kernel(tbl_ref, len_ref, *refs, bs: int, scale: float,
     base = len_ref[b]                                     # valid cache length
 
     # ---- fused window-writeback epilogue -------------------------------
-    # Merge the W fresh rows into this tile at their in-block offsets and
-    # write the merged tile to the aliased pool outputs. The out index_map
-    # routes non-straddling tiles to the sink, so only the O(W) window
-    # blocks are really committed; writing unconditionally keeps the out
-    # VMEM buffer coherent with whatever block the emission targets.
-    off = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)[:, 0] \
-        - base                                            # (bs,)
-    k_tile = k1_ref[0, :, 0, :]                           # (bs, dk) raw dtype
-    kn = n1_ref[0, :, 0, :]                               # (W, dk)
-    k_merged = _merge_window(k_tile, kn, off, True, W)
-    ok1_ref[0, :, 0, :] = k_merged
+    # Merge the W fresh rows into this tile (all kv heads at once) at their
+    # in-block offsets and write the merged tile to the aliased pool
+    # outputs. The out index_map routes non-straddling tiles to the sink, so
+    # only the O(W) window blocks are really committed; writing
+    # unconditionally keeps the out VMEM buffer coherent with whatever block
+    # the emission targets. The attention below reads the merged tiles back
+    # from these output buffers, one kv head at a time.
+    first = j * bs - base
+    ok1_ref[0] = _merge_window(k1_ref[0], n1_ref[0], first, True, W)
     if latent:
-        k2_tile = k2_ref[0, :, 0, :]
-        k2n = n2_ref[0, :, 0, :]
-        k2_merged = _merge_window(k2_tile, k2n, off, True, W)
-        ok2_ref[0, :, 0, :] = k2_merged
-        v_merged = k_merged                               # c_kv doubles as V
+        ok2_ref[0] = _merge_window(k2_ref[0], n2_ref[0], first, True, W)
+        v_out = ok1_ref                                   # c_kv doubles as V
     else:
-        v_tile = v_ref[0, :, 0, :]
-        vn = n2_ref[0, :, 0, :]
-        v_merged = _merge_window(v_tile, vn, off, True, W)
-        ok2_ref[0, :, 0, :] = v_merged
-        k2_merged = None
+        ok2_ref[0] = _merge_window(v_ref[0], n2_ref[0], first, True, W)
+        v_out = ok2_ref
 
     # skip fully-masked tiles outright: tail tiles past the last query
     # position (sink-aliased table entries) and, under a sliding window,
@@ -142,49 +138,97 @@ def _paged_kernel(tbl_ref, len_ref, *refs, bs: int, scale: float,
 
     @pl.when(visible)
     def _tile():
-        q = q1_ref[0, 0].astype(jnp.float32)              # (R, dk) R = G*W
-        k = k_merged.astype(jnp.float32)                  # (bs, dk)
-        R = q.shape[0]
-        s = (q @ k.T) * scale                             # (R, bs)
-        if latent:
-            q2 = q2_ref[0, 0].astype(jnp.float32)         # (R, dr)
-            k2 = k2_merged.astype(jnp.float32)            # (bs, dr)
-            s += (q2 @ k2.T) * scale
-
         # row r serves window query w = r % W (G heads share a kv head)
+        R = q1_ref.shape[2]                               # G*W
         q_pos = base + jax.lax.broadcasted_iota(jnp.int32, (R, bs), 0) % W
         k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (R, bs), 1)
         mask = k_pos <= q_pos
         if window > 0:
             mask &= k_pos > (q_pos - window)
-        s = jnp.where(mask, s, NEG)
 
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=1)
-        v = v_merged.astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + p @ v
-        m_ref[...] = m_new
+        for h in range(KV):                               # static unroll
+            q = q1_ref[0, h].astype(jnp.float32)          # (R, dk)
+            k = ok1_ref[0, :, h, :].astype(jnp.float32)   # (bs, dk)
+            s = (q @ k.T) * scale                         # (R, bs)
+            if latent:
+                q2 = q2_ref[0, h].astype(jnp.float32)     # (R, dr)
+                k2 = ok2_ref[0, :, h, :].astype(jnp.float32)   # (bs, dr)
+                s += (q2 @ k2.T) * scale
+            s = jnp.where(mask, s, NEG)
+
+            m_prev, l_prev = m_ref[h], l_ref[h]           # (R, 1)
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            v = v_out[0, :, h, :].astype(jnp.float32)     # (bs, dv)
+            acc_ref[h] = acc_ref[h] * alpha + p @ v
+            m_ref[h] = m_new
 
     @pl.when(j == nj - 1)
     def _emit():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def _pool_out_map(bs: int, W: int):
     """Out index_map for an aliased pool output: window-straddling tiles go
     to their physical block, everything else to the reserved sink 0 (whose
     contents are garbage by design) — pool writes stay O(B*W) per round."""
-    def index_map(b, h, j, tbl, ln):
+    def index_map(b, j, tbl, ln):
         base = ln[b]
         straddle = (j * bs <= base + W - 1) & ((j + 1) * bs > base)
-        return (jnp.where(straddle, tbl[b, j], 0), 0, h, 0)
+        return (jnp.where(straddle, tbl[b, j], 0), 0, 0, 0)
     return index_map
+
+
+def _paged_call(qs, pools, news, tables, lengths, *, W: int, window: int,
+                scale: float, latent: bool, interpret: bool):
+    """One pallas_call over grid (B, nb) for both variants. ``qs``: query
+    operands ``(B, KV, R, d_i)``; ``pools``: the two ``(P, bs, KV, d_i)``
+    pools, committed in place; ``news``: their ``(B, W, KV, d_i)`` window
+    rows. Every pool and window block spans all KV heads, so its last two
+    dims equal the array's (Mosaic's tiling rule holds for any KV)."""
+    B, KV, R, _ = qs[0].shape
+    bs = pools[0].shape[1]
+    nb = tables.shape[1]
+    dv = pools[0].shape[-1] if latent else pools[1].shape[-1]
+
+    def row(b, j, tbl, ln):
+        return (b, 0, 0, 0)
+
+    def blk(b, j, tbl, ln):
+        return (tbl[b, j], 0, 0, 0)
+
+    pool_map = _pool_out_map(bs, W)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, nb),
+        in_specs=([pl.BlockSpec((1,) + q.shape[1:], row) for q in qs]
+                  + [pl.BlockSpec((1, bs) + p.shape[2:], blk) for p in pools]
+                  + [pl.BlockSpec((1, W) + n.shape[2:], row) for n in news]),
+        out_specs=([pl.BlockSpec((1, KV, R, dv), row)]
+                   + [pl.BlockSpec((1, bs) + p.shape[2:], pool_map)
+                      for p in pools]),
+        scratch_shapes=[
+            pltpu.VMEM((KV, R, 1), jnp.float32),          # running max
+            pltpu.VMEM((KV, R, 1), jnp.float32),          # running sum
+            pltpu.VMEM((KV, R, dv), jnp.float32),         # accumulator
+        ],
+    )
+    nq = len(qs)
+    return pl.pallas_call(
+        functools.partial(_paged_kernel, bs=bs, scale=scale, window=window,
+                          W=W, latent=latent),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, KV, R, dv), qs[0].dtype)]
+                  + [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        # flat operands: (tables, lengths, *qs, *pools, *news)
+        input_output_aliases={2 + nq: 1, 3 + nq: 2},
+        interpret=interpret,
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *qs, *pools,
+      *news)
 
 
 @functools.partial(jax.jit, static_argnames=("W", "window", "scale",
@@ -198,50 +242,11 @@ def paged_decode_kernel(q, k_pool, v_pool, k_new, v_new, tables, lengths, *,
     tables: (B, nb) physical block ids; lengths: (B,) valid prefix lengths.
     Query w attends keys < lengths + w + 1. Returns (out (B, KV, G*W, dv),
     k_pool, v_pool) with the pools updated in place (aliased)."""
-    B, KV, R, dk = q.shape
-    P, bs = k_pool.shape[:2]
-    nb = tables.shape[1]
-    dv = v_pool.shape[-1]
     if scale is None:
-        scale = 1.0 / dk ** 0.5
-
-    pool_map = _pool_out_map(bs, W)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KV, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, R, dk), lambda b, h, j, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, dk),
-                         lambda b, h, j, tbl, ln: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, dv),
-                         lambda b, h, j, tbl, ln: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, W, 1, dk), lambda b, h, j, tbl, ln: (b, 0, h, 0)),
-            pl.BlockSpec((1, W, 1, dv), lambda b, h, j, tbl, ln: (b, 0, h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, R, dv),
-                         lambda b, h, j, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, dk), pool_map),
-            pl.BlockSpec((1, bs, 1, dv), pool_map),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((R,), jnp.float32),
-            pltpu.VMEM((R,), jnp.float32),
-            pltpu.VMEM((R, dv), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_kernel, bs=bs, scale=scale, window=window,
-                          W=W, latent=False),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, KV, R, dv), q.dtype),
-                   jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
-        # flat operands: (tables, lengths, q, k_pool, v_pool, k_new, v_new)
-        input_output_aliases={3: 1, 4: 2},
-        interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pool, v_pool,
-      k_new, v_new)
+        scale = 1.0 / q.shape[-1] ** 0.5
+    return _paged_call((q,), (k_pool, v_pool), (k_new, v_new), tables,
+                       lengths, W=W, window=window, scale=scale,
+                       latent=False, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("W", "scale", "interpret"))
@@ -254,49 +259,9 @@ def paged_latent_kernel(q_lat, q_rope, c_pool, kr_pool, c_new, kr_new,
     products; the output is the attention-weighted *latent* (B, 1, H*W, r) —
     the merged c_kv tile doubles as the value. Returns (out, c_pool,
     kr_pool) with both latent pools committed in place (aliased)."""
-    B, _, R, r = q_lat.shape
-    P, bs = c_pool.shape[:2]
-    dr = q_rope.shape[-1]
-    nb = tables.shape[1]
-
-    pool_map = _pool_out_map(bs, W)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, 1, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, R, r), lambda b, h, j, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, R, dr), lambda b, h, j, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, r),
-                         lambda b, h, j, tbl, ln: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, dr),
-                         lambda b, h, j, tbl, ln: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, W, 1, r), lambda b, h, j, tbl, ln: (b, 0, h, 0)),
-            pl.BlockSpec((1, W, 1, dr), lambda b, h, j, tbl, ln: (b, 0, h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, R, r), lambda b, h, j, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, r), pool_map),
-            pl.BlockSpec((1, bs, 1, dr), pool_map),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((R,), jnp.float32),
-            pltpu.VMEM((R,), jnp.float32),
-            pltpu.VMEM((R, r), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_kernel, bs=bs, scale=scale, window=0,
-                          W=W, latent=True),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, 1, R, r), q_lat.dtype),
-                   jax.ShapeDtypeStruct(c_pool.shape, c_pool.dtype),
-                   jax.ShapeDtypeStruct(kr_pool.shape, kr_pool.dtype)],
-        # flat operands: (tbl, len, q_lat, q_rope, c_pool, kr_pool, c_new,
-        #                 kr_new)
-        input_output_aliases={4: 1, 5: 2},
-        interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q_lat, q_rope, c_pool, kr_pool, c_new, kr_new)
+    return _paged_call((q_lat, q_rope), (c_pool, kr_pool), (c_new, kr_new),
+                       tables, lengths, W=W, window=0, scale=scale,
+                       latent=True, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -311,9 +276,8 @@ def _write_kernel_body(tbl_ref, st_ref, act_ref, pool_ref, new_ref, out_ref,
     blk = start // bs + t
     last = (start + W - 1) // bs
     valid = (blk < nb) & (blk <= last) & (act_ref[b] > 0)
-    off = blk * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)[:, 0] \
-        - start
-    out_ref[0] = _merge_window(pool_ref[0], new_ref[0], off, valid, W)
+    out_ref[0] = _merge_window(pool_ref[0], new_ref[0], blk * bs - start,
+                               valid, W)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -35,8 +35,9 @@ def _verify_kernel(logits_ref, eps_ref, out_ref, m_ref, a_ref, *, bv: int):
 
     vals = (logits_ref[...].astype(jnp.float32)
             + eps_ref[...].astype(jnp.float32))          # (br, bv)
-    blk_max = jnp.max(vals, axis=1)                      # (br,)
-    blk_arg = jnp.argmax(vals, axis=1).astype(jnp.int32) + j * bv
+    blk_max = jnp.max(vals, axis=1, keepdims=True)       # (br, 1)
+    blk_arg = (jnp.argmax(vals, axis=1, keepdims=True).astype(jnp.int32)
+               + j * bv)
 
     run_max = m_ref[...]
     take = blk_max > run_max                             # strict: first wins
@@ -71,12 +72,13 @@ def spec_verify_kernel(logits, eps, block_rows: int = 8,
             pl.BlockSpec((br, bv), lambda i, j: (i, j)),
             pl.BlockSpec((br, bv), lambda i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((br,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Rp,), jnp.int32),
+        # rank-2 (br, 1) out/scratch blocks: Mosaic refuses rank-1 ones
+        out_specs=pl.BlockSpec((br, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Rp, 1), jnp.int32),
         scratch_shapes=[
-            pltpu.VMEM((br,), jnp.float32),   # running max
-            pltpu.VMEM((br,), jnp.int32),     # running argmax
+            pltpu.VMEM((br, 1), jnp.float32),   # running max
+            pltpu.VMEM((br, 1), jnp.int32),     # running argmax
         ],
         interpret=interpret,
     )(logits, eps)
-    return out[:R]
+    return out[:R, 0]

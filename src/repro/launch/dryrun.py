@@ -27,9 +27,8 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-
 from repro.configs import ARCHS, SHAPES, get_config, shape_applicable
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.train import make_optimizer, shard_jit_train_step
 from repro.launch.serve import make_serve_step
@@ -249,6 +248,7 @@ def main(argv=None):
     ap.add_argument("--out", default=os.path.normpath(ART_DIR))
     args = ap.parse_args(argv)
     os.makedirs(args.out, exist_ok=True)
+    use_compile_cache()
 
     if args.all:
         jobs = [(a, s, mp)

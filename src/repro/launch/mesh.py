@@ -7,18 +7,26 @@ host devices via XLA_FLAGS before any jax import)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: placement comes from the
+    shardings the programs are given (GSPMD), as the sharding rules and the
+    serving topology expect — ``make_mesh`` defaults to ``Explicit`` axes."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Tiny mesh over however many (host) devices exist — for sharding unit
-    tests with xla_force_host_platform_device_count."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """Mesh over the first ``data * model`` devices — the host devices of
+    xla_force_host_platform_device_count in tests, the chips on a TPU host."""
+    return auto_mesh((data, model), ("data", "model"))
 
 
 # TPU v5e hardware constants for the roofline analysis (per chip)

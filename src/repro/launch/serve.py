@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import sys
 import time
 
 import jax
@@ -37,6 +38,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.reparam import reparam_argmax
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.transformer import TransformerLM
 from repro.serving import (FaultPlan, Request, ServingEngine,
                            ServingTopology)
@@ -208,6 +210,7 @@ def main(argv=None):
                          "prefix instead of re-hitting it on disk)")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     params = TransformerLM.init(jax.random.PRNGKey(0), cfg)
     topo = ServingTopology() if args.mesh is None \
@@ -256,8 +259,12 @@ def main(argv=None):
     dt = time.time() - t0
     engine.close()
     m = engine.export_metrics()
-    total_new = sum(r.new_tokens for r in done)
-    print(f"served {len(done)} requests / {total_new} tokens "
+    # rejected submits and failed requests are delivered through ``done``
+    # too, with ``error`` set and no result
+    failed = [r for r in done if r.error is not None]
+    served = [r for r in done if r.error is None]
+    total_new = sum(r.new_tokens for r in served)
+    print(f"served {len(served)} requests / {total_new} tokens "
           f"in {m['rounds']} verify rounds ({dt:.1f}s)")
     print(f"ARM calls vs ancestral baseline: "
           f"{100.0 * m['arm_calls_vs_ancestral']:.1f}% "
@@ -266,10 +273,13 @@ def main(argv=None):
     print("telemetry: " + json.dumps(
         {k: (round(v, 4) if isinstance(v, float) else v)
          for k, v in m.items()}, indent=2))
-    for r in done[:3]:
+    for r in served[:3]:
         print(f"  req {r.uid}: calls={r.calls_used} "
               f"prefill={r.prefill_calls} tokens={r.result[:12]}…")
+    for r in failed:
+        print(f"request {r.uid} failed: {r.error}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -22,6 +22,7 @@ from repro.data.synthetic import token_batches
 from repro.models import frontends
 from repro.models.losses import lm_loss
 from repro.models.transformer import TransformerLM
+from repro.launch.mesh import auto_mesh
 from repro.sharding import use_rules
 from repro.sharding.rules import (batch_sharding, default_activation_rules,
                                   param_shardings, replicated)
@@ -133,7 +134,7 @@ def main(argv=None):
 
     cfg = get_config(args.arch, reduced=args.reduced)
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+    mesh = auto_mesh((n_dev, 1), ("data", "model"))
     optimizer = make_optimizer(cfg, args.steps, args.lr)
 
     key = jax.random.PRNGKey(0)

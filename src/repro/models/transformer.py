@@ -579,7 +579,8 @@ class TransformerLM:
 
     @staticmethod
     def scatter_paged(cfg: ModelConfig, paged, dense_new, tables, rows,
-                      start, width: int, active):
+                      start, width: int, active,
+                      interpret: Optional[bool] = None):
         """Write a dense view's ``[start, start + width)`` positions back into
         the physical pool through the same aliased ``paged_window_write``
         kernel the fused round uses, so donation semantics are uniform: only
@@ -588,7 +589,9 @@ class TransformerLM:
         of inactive rows (and slots past the span) are routed to the
         reserved sink block 0. Recurrent state leaves are adopted
         unconditionally for every view row (mirrors the dense engine, where
-        an inactive row's re-run reproduces its snapshot bit-for-bit)."""
+        an inactive row's re-run reproduces its snapshot bit-for-bit).
+        ``interpret`` is the writeback kernel's mode (None: compiled on
+        TPU only)."""
         from repro.kernels.paged_attention.ops import paged_window_write
 
         act = active.astype(jnp.int32)
@@ -606,10 +609,11 @@ class TransformerLM:
                 def body(_, pd):
                     p_l, d_l = pd
                     return None, paged_window_write(p_l, span(d_l), tables,
-                                                    start, act)
+                                                    start, act, interpret)
                 _, out = jax.lax.scan(body, None, (pleaf, dleaf))
                 return out
-            return paged_window_write(pleaf, span(dleaf), tables, start, act)
+            return paged_window_write(pleaf, span(dleaf), tables, start, act,
+                                      interpret)
 
         def rec(stacked, pleaf, dleaf):
             if stacked:

@@ -222,9 +222,16 @@ class ServingEngine:
         # the round hot path). The Pallas kernel is the compiled TPU fast
         # path; elsewhere the default is the gather-view fallback, which is
         # bit-exact vs the dense engine (resolve_interpret's dispatch).
+        # GSPMD cannot partition a compiled Mosaic kernel, and a mesh
+        # engine's prefill (and a tensor-parallel round) is a GSPMD program:
+        # on a mesh every Pallas call (the gather-view path's aliased
+        # writeback included) runs in interpret mode, as plain XLA ops, and
+        # attention defaults to the gather-view path.
         self.paged_attention = paged_attention
+        on_mesh = topology is not None and topology.mesh is not None
+        self.kernel_interpret = True if on_mesh else None
         if use_attention_kernel is None:
-            use_attention_kernel = not resolve_interpret(None)
+            use_attention_kernel = not on_mesh and not resolve_interpret(None)
         self.use_attention_kernel = use_attention_kernel
         # donate the pool + per-slot state into the jitted round/prefill
         # steps (their previous values are dead once the step returns)
@@ -547,8 +554,7 @@ class ServingEngine:
                 def one_round(paged, tokens, n, cand):
                     if self.paged_attention:
                         cache = paged
-                        pv = PagedView(tables, rows,
-                                       self.use_attention_kernel)
+                        pv = self._view(tables, rows)
                     else:
                         cache = TransformerLM.gather_paged(cfg, paged,
                                                            tables, rows)
@@ -568,7 +574,8 @@ class ServingEngine:
                         active = n < target
                         paged2 = TransformerLM.scatter_paged(
                             cfg, paged, st2.cache, tables, rows,
-                            jnp.maximum(n - 1, 0), W, active)
+                            jnp.maximum(n - 1, 0), W, active,
+                            interpret=self.kernel_interpret)
                     cand2 = jnp.zeros_like(cand).at[:, :W].set(st2.cand)
                     return paged2, st2.tokens, st2.n, cand2, rstats
 
@@ -647,7 +654,7 @@ class ServingEngine:
                           poison, plen):
                 if self.paged_attention:
                     cache = paged
-                    pv = PagedView(tables, rows, self.use_attention_kernel)
+                    pv = self._view(tables, rows)
                 else:
                     cache = TransformerLM.gather_paged(cfg, paged,
                                                        tables, rows)
@@ -667,7 +674,8 @@ class ServingEngine:
                     active = n < target
                     paged2 = TransformerLM.scatter_paged(
                         cfg, paged, st2.cache, tables, rows,
-                        jnp.maximum(n - 1, 0), W, active)
+                        jnp.maximum(n - 1, 0), W, active,
+                        interpret=self.kernel_interpret)
                 cand2 = jnp.zeros_like(cand).at[:, :W].set(st2.cand)
                 return paged2, st2.tokens, st2.n, cand2, rstats
 
@@ -823,6 +831,12 @@ class ServingEngine:
         return {"tensor_parallel": bool(self.topo.auto_axes),
                 "pool_scatter_shapes": frozenset(shapes)}
 
+    def _view(self, tables, rows) -> PagedView:
+        """Block-table view for a paged decode through this engine's
+        attention path (kernel or gather view, compiled or interpreted)."""
+        return PagedView(tables, rows, self.use_attention_kernel,
+                         self.kernel_interpret)
+
     def _round_args(self) -> tuple:
         """Positional args of the jitted round loop, in ABI order — the one
         place that order is written down (tests and benches that drive the
@@ -861,8 +875,7 @@ class ServingEngine:
 
             def fn(params, paged, table_row, row, chunk, start):
                 if self.paged_attention:
-                    view = PagedView(table_row, row,
-                                     self.use_attention_kernel)
+                    view = self._view(table_row, row)
                     _, _, nc = TransformerLM.decode_window_paged(
                         params, cfg, chunk, paged, view, start)
                     sel = TransformerLM.select_states(
@@ -876,7 +889,7 @@ class ServingEngine:
                     cfg, nc, jnp.full((1,), C, jnp.int32))
                 return TransformerLM.scatter_paged(
                     cfg, paged, sel, table_row, row, start, C,
-                    jnp.ones((1,), bool))
+                    jnp.ones((1,), bool), interpret=self.kernel_interpret)
 
             kw = {}
             sh = self.topo.paged_shardings(cfg, self.paged)

@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.sharding.api import shard_map
 
 
 class ServingTopology:
@@ -64,9 +63,11 @@ class ServingTopology:
 
     @property
     def auto_axes(self) -> frozenset:
-        """Mesh axes left to GSPMD (tensor-parallel params live there).
-        Size-1 axes are excluded: they are trivially manual, and a jax whose
-        shard_map lacks ``auto`` support can still serve data-parallel."""
+        """Mesh axes of size > 1 besides ``data_axis``: the axes that carry
+        tensor-parallel params. The round's ``shard_map`` is manual over
+        ``data_axis`` alone, so GSPMD partitions these (and any size-1 axis)
+        automatically; an empty set means the engine is purely
+        data-parallel."""
         if self.mesh is None:
             return frozenset()
         return frozenset(a for a in self.mesh.axis_names
@@ -173,8 +174,8 @@ class ServingTopology:
         if self.mesh is None:
             return fn
         d = self.batch_spec()
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=self.mesh,
             in_specs=(P(), paged_specs) + (d,) * n_batch_in,
             out_specs=(paged_specs,) + (d,) * n_batch_out,
-            check_vma=False, auto=self.auto_axes)
+            axis_names={self.data_axis}, check_vma=False)

@@ -12,38 +12,12 @@ mesh-axis names (e.g. batch -> ("pod", "data")).
 from __future__ import annotations
 
 import contextlib
-import inspect
 import threading
 from dataclasses import dataclass, field
 from typing import Mapping, Sequence
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:                      # jax < 0.6 keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SM_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, **kwargs):
-    """Version-portable ``shard_map``: maps the renamed replication-check
-    kwarg (``check_rep`` <-> ``check_vma``) onto whatever the installed jax
-    accepts. Shared by the MoE expert-parallel path and the serving
-    topology layer."""
-    for old, new in (("check_vma", "check_rep"), ("check_rep", "check_vma")):
-        if old in kwargs and old not in _SM_PARAMS:
-            kwargs[new] = kwargs.pop(old)
-    if "auto" in kwargs and "auto" not in _SM_PARAMS:
-        if kwargs["auto"]:
-            raise NotImplementedError(
-                "this jax's shard_map has no `auto` axes; "
-                "tensor-parallel serving needs it")
-        del kwargs["auto"]
-    return _shard_map(f, **kwargs)
-
 
 _STATE = threading.local()
 

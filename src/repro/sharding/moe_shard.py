@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.moe import MoE, _mlp_apply
-from repro.sharding.api import shard_map
 
 
 def _dp_axes(mesh):
@@ -136,7 +135,7 @@ def moe_apply_sharded(p, x, cfg, mesh, capacity_factor, ep_only: bool = False):
         return y.reshape(b, T, D), aux
 
     gate_arg = p["experts"]["gate"] if has_gate else p["experts"]["up"]
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(r_spec, w_spec, w_spec, w_spec, x_spec),
         out_specs=(x_spec, P()),
@@ -211,7 +210,7 @@ def _moe_ep_only(p, x, cfg, mesh, capacity_factor, e_axes, n_eshards, dp):
         return y.reshape(b, T, D), aux
 
     gate_arg = p["experts"]["gate"] if has_gate else p["experts"]["up"]
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), w_spec, w_spec, w_spec, x_spec),
         out_specs=(x_spec, P()),

@@ -70,7 +70,7 @@ def test_unaliased_donation_fixture_fails():
 def test_f64_leak_fixture_fails():
     def fn(x):
         return x.astype("float64") * 2.0
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rep = check_program(fn, (jnp.ones((3,), jnp.float32),),
                             Contract("T", [NoF64Leaks()]))
     assert not rep.ok
@@ -126,12 +126,11 @@ COLLECTIVE_SCRIPT = textwrap.dedent("""
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.analysis import Contract, NoCollectives, check_program
-    from repro.sharding.api import shard_map
 
     mesh = Mesh(np.array(jax.devices()).reshape(2,), ("data",))
 
     def fn(x):
-        return shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
+        return jax.shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
                          in_specs=P("data"), out_specs=P())(x)
 
     rep = check_program(fn, (jnp.arange(8, dtype=jnp.float32),),

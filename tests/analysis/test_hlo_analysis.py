@@ -51,7 +51,7 @@ def test_counts_recurse_into_pjit():
         _jaxpr(fn, jnp.zeros((4, 2, 8)), jnp.int32(1)),
         ("scatter",), min_rank=3)
     assert len(sites) == 1
-    assert "pjit" in sites[0].path       # evidence names the nesting
+    assert "jit" in sites[0].path        # evidence names the nesting
 
 
 def test_counts_recurse_into_pallas_body():
@@ -91,7 +91,7 @@ def test_rank_filter_separates_pool_from_bookkeeping():
 def test_find_dtype_leaks_under_x64():
     def fn(x):
         return x.astype("float64") * 2.0
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jx = jax.jit(fn).trace(jnp.ones((3,), jnp.float32)).jaxpr
     leaks = find_dtype_leaks(jx)
     assert leaks and all("float64" not in s.primitive for s in leaks)

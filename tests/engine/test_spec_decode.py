@@ -138,3 +138,15 @@ def test_low_memory_serve_step_equivalence(arch):
     for x, y in zip(jax.tree.leaves(c1), jax.tree.leaves(c2)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                    rtol=2e-4, atol=2e-5)
+
+
+def test_sampler_round_takes_params_as_arguments():
+    """The jitted round gets the weights as an argument: weights it closed
+    over would be lowered into the program as constants (gigabytes at
+    published widths, which exhausts the host while compiling)."""
+    cfg, params = _make("qwen3-1.7b")
+    s = PredictiveSampler(cfg, params, window=4, max_len=32)
+    state = s.init_state(jnp.ones((1, 3), jnp.int32), 1)
+    traced = s._round.trace(s.params, state, jnp.full((1,), 8, jnp.int32))
+    n_params = sum(x.size for x in jax.tree.leaves(params))
+    assert sum(np.size(c) for c in traced.jaxpr.consts) < n_params

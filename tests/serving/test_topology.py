@@ -1,6 +1,7 @@
-"""Topology/router units — all host-side (no devices, no mesh): slot and
-block-pool partition math, pool-pressure admission routing, per-shard stats
-merging, and the priority/EDF/FIFO queue order."""
+"""Topology/router units — host-side: slot and block-pool partition math,
+pool-pressure admission routing, per-shard stats merging, the
+priority/EDF/FIFO queue order, and the engine's kernel dispatch on a
+(1x1) mesh."""
 import numpy as np
 import pytest
 
@@ -114,3 +115,27 @@ def test_deadline_time_and_miss_flag():
     assert not d.missed_deadline
     d.finish_time = 102.5
     assert d.missed_deadline
+
+
+def test_mesh_engine_interprets_pallas_calls():
+    """GSPMD cannot partition a compiled Mosaic kernel, so an engine on a
+    mesh (even 1x1) interprets every Pallas call and attends through the
+    gather view; a single-device engine leaves the choice to the backend."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.launch.mesh import make_host_mesh
+    from repro.models.transformer import TransformerLM
+    from repro.serving import ServingEngine
+
+    cfg = get_config("qwen3-1.7b", reduced=True)
+    params = TransformerLM.init(jax.random.PRNGKey(0), cfg)
+    kw = dict(batch=1, max_len=32, block_size=4)
+    solo = ServingEngine(cfg, params, **kw)
+    assert solo.kernel_interpret is None
+    assert solo._view(None, None).interpret is None
+    meshed = ServingEngine(cfg, params, **kw,
+                           topology=ServingTopology(make_host_mesh(1, 1)))
+    assert meshed.kernel_interpret is True
+    assert meshed.use_attention_kernel is False
+    assert meshed._view(None, None) == (None, None, False, True)

@@ -99,3 +99,49 @@ def test_no_tp_rules_shard_everything_validly():
                 continue
             n = 256 if isinstance(ax, tuple) else 16
             assert leaf.shape[dim] % n == 0, (names, leaf.shape, spec)
+
+
+def test_serve_exits_nonzero_and_reports_rejected_request(monkeypatch,
+                                                         capsys):
+    """A request that fails or is rejected makes the launcher exit
+    nonzero, naming the request and its structured error."""
+    from repro.launch import serve
+    monkeypatch.setattr(serve, "use_compile_cache", lambda: None)
+    rc = serve.main(["--arch", "qwen3-1.7b", "--reduced", "--requests", "1",
+                     "--new-tokens", "100", "--max-len", "32"])
+    assert rc == 1
+    err = capsys.readouterr().err
+    assert "request 0 failed: too_long" in err
+
+
+def test_serve_exits_zero_when_every_request_is_served(monkeypatch, capsys):
+    from repro.launch import serve
+    monkeypatch.setattr(serve, "use_compile_cache", lambda: None)
+    rc = serve.main(["--arch", "qwen3-1.7b", "--reduced", "--requests", "2",
+                     "--new-tokens", "4", "--max-len", "32", "--batch", "2"])
+    assert rc == 0
+    assert "served 2 requests / 8 tokens" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("env", [None, "/srv/jax-cache"])
+def test_compile_cache_dir_is_fixed_or_from_env(monkeypatch, env):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without it
+    the cache goes to the checkout's git-ignored ``.jax_cache``."""
+    import pathlib
+
+    from repro.launch import compile_cache
+    set_calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: set_calls.append(a))
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    got = compile_cache.use_compile_cache()
+    root = pathlib.Path(__file__).resolve().parents[1]
+    if env is None:
+        assert got == str(root / ".jax_cache")
+        assert set_calls == [("jax_compilation_cache_dir", got)]
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    else:
+        assert got == env and set_calls == []
