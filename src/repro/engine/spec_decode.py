@@ -158,6 +158,7 @@ class PredictiveSampler:
 # ---------------------------------------------------------------------------
 
 @hot_path
+@jax.named_scope("verify_round")
 def verify_round(params, cfg, eps_fn, state: GenState, target_len,
                  use_forecast_heads: bool = False,
                  use_verify_kernel: bool = False,
@@ -216,7 +217,8 @@ def verify_round(params, cfg, eps_fn, state: GenState, target_len,
     nonfinite = 1 - jnp.all(jnp.isfinite(logits),
                             axis=(1, 2)).astype(jnp.int32)
     out_pos = state.n[:, None] + jnp.arange(W)[None, :]   # sampled positions
-    eps = eps_fn(state.seq_ids, out_pos)
+    with jax.named_scope("noise"):
+        eps = eps_fn(state.seq_ids, out_pos)
     if use_verify_kernel:
         from repro.kernels.spec_verify.ops import spec_verify
         out = spec_verify(logits, eps)                    # (B, W)
@@ -284,7 +286,9 @@ def verify_round(params, cfg, eps_fn, state: GenState, target_len,
         T = cfg.forecast_horizon
         s_idx = jnp.arange(W)
         t_of_s = jnp.clip(s_idx, 0, T - 1)
-        eps_next = eps_fn(state.seq_ids, n_new[:, None] - 1 + s_idx[None, :])
+        with jax.named_scope("noise"):
+            eps_next = eps_fn(state.seq_ids,
+                              n_new[:, None] - 1 + s_idx[None, :])
         fc_tok = reparam_argmax(
             jnp.take_along_axis(
                 fc_a, jnp.broadcast_to(t_of_s[None, :, None],

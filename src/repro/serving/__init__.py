@@ -14,7 +14,8 @@ parking + bitwise-exact resume, ``ParkedSequence``), and shard rebalancing
 through a ``FaultPlan``; durability (§16) journals the request lifecycle
 (``RequestJournal``), spills arena victims to a ``DiskTier``, and
 checkpoints the scheduler so a SIGKILLed engine restarts bitwise-exact
-(``REPRO_KILL_POINT`` crash harness).
+(``REPRO_KILL_POINT`` crash harness). Telemetry is ``EngineMetrics``'
+counters and a bounded ``SpanLog`` of the engine's host phases.
 """
 from repro.serving.admission import (AdmissionQueue, Request, pow2_at_most,
                                      prefill_chunks)
@@ -26,13 +27,15 @@ from repro.serving.faults import (KILL_POINTS, CircuitBreaker, FaultPlan,
 from repro.serving.hostcache import (DiskTier, HostArena, HostTier,
                                      StagingRing)
 from repro.serving.journal import RequestJournal
-from repro.serving.metrics import EngineMetrics, percentile
+from repro.serving.metrics import (EngineMetrics, Span, SpanLog,
+                                   default_span_log, percentile)
 from repro.serving.topology import ServingTopology
 
 __all__ = ["AdmissionQueue", "Request", "prefill_chunks", "pow2_at_most",
            "AdaptiveWindowController", "BlockManager", "ShardedBlockPool",
            "chain_hashes", "ParkedSequence", "ServingEngine",
-           "EngineMetrics", "percentile", "ServingTopology",
+           "EngineMetrics", "Span", "SpanLog", "default_span_log",
+           "percentile", "ServingTopology",
            "HostArena", "HostTier", "StagingRing", "DiskTier",
            "RequestJournal", "KILL_POINTS", "kill_point",
            "CircuitBreaker", "FaultPlan", "RequestError", "StagingFault"]

@@ -84,7 +84,11 @@ slot and blocks immediately. What's new over the dense batcher:
 * **Telemetry** — per-request latency/accept/ARM-call counters, deadline
   (SLO) misses — including expiries detected while still queued/parked —
   preemption/migration/aging counters, and engine gauges exported as plain
-  dicts (``EngineMetrics``).
+  dicts (``EngineMetrics``); host spans of each step's phases (admission,
+  prefix lookup, block allocation and spills, prefill and round dispatch,
+  sync, harvest) in a bounded ``SpanLog``, and named scopes
+  (``serve.round_loop``, ``serve.prefill``) on the device programs, both
+  visible in a profile.
 
 Exactness: every path emits tokens bit-identical to a per-request
 ``PredictiveSampler.generate`` run with the same eps key and noise-stream id
@@ -93,6 +97,7 @@ mesh paths, tests/serving/test_mesh_engine.py.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import time
@@ -119,8 +124,10 @@ from repro.serving.faults import (CircuitBreaker, FaultPlan, RequestError,
                                   kill_point)
 from repro.serving.hostcache import DiskTier
 from repro.serving.journal import RequestJournal
-from repro.serving.metrics import EngineMetrics
+from repro.serving.metrics import EngineMetrics, default_span_log
 from repro.serving.topology import ServingTopology
+
+_ENGINE_IDS = itertools.count()     # the small integer naming each engine
 
 
 def _has_recurrent(cfg) -> bool:
@@ -369,6 +376,9 @@ class ServingEngine:
         self.controller = AdaptiveWindowController(
             w_max=window_max, w_init=window_init, enabled=adaptive)
         self.metrics = EngineMetrics()
+        # host spans of each step's phases go to the process's span log
+        self._engine_no = next(_ENGINE_IDS)
+        self._step_no = 0
         self.queue = AdmissionQueue()
         self.slots: list[Optional[Request]] = [None] * batch
         self.done: list[Request] = []
@@ -486,6 +496,13 @@ class ServingEngine:
                       noise_seed=req.noise_seed, rank=int(req._seq))
         return True
 
+    def _span(self, name: str, uid: Optional[int] = None):
+        """A span of this engine's host work in the process's span log,
+        stamped with the engine and the current step (``uid`` on request
+        spans)."""
+        return default_span_log().span(name, step=self._step_no, uid=uid,
+                                       engine=self._engine_no)
+
     def _journal(self, type: str, **fields):
         """Append one lifecycle record when a journal is configured
         (DESIGN.md §16); a no-op for volatile engines."""
@@ -546,6 +563,7 @@ class ServingEngine:
                 return self._round_fns[(W, k)]
             cfg = self.cfg
 
+            @jax.named_scope("serve.round_loop")
             def fn(params, paged, tables, tokens, n, cand, seq_ids, target,
                    poison):
                 R = tokens.shape[0]          # rows on this shard (B/D)
@@ -640,6 +658,7 @@ class ServingEngine:
         two bounds; ``r < k`` caps the trip count regardless)."""
         cfg = self.cfg
 
+        @jax.named_scope("serve.round_loop")
         def fn(params, paged, tables, tokens, n, cand, seq_ids, target,
                poison, plen, d_valid, d_tables, d_tokens, d_n, d_target,
                d_seq, d_poison, d_plen, q_more):
@@ -873,6 +892,7 @@ class ServingEngine:
         if C not in self._prefill_fns:
             cfg = self.cfg
 
+            @jax.named_scope("serve.prefill")
             def fn(params, paged, table_row, row, chunk, start):
                 if self.paged_attention:
                     view = self._view(table_row, row)
@@ -1107,8 +1127,9 @@ class ServingEngine:
         def hook(local_bid: int, key) -> bool:
             if self.tier.has_kv(shard, key):
                 return True
-            rows = self._collect_block_payload([local_bid + off])[0]
-            return self.tier.put_kv(shard, key, rows)
+            with self._span("serve.spill"):
+                rows = self._collect_block_payload([local_bid + off])[0]
+                return self.tier.put_kv(shard, key, rows)
 
         return hook
 
@@ -2034,7 +2055,9 @@ class ServingEngine:
                 if b is not None:
                     self.queue.remove(req)
                     try:
-                        self._admit(req, b)
+                        with self._span("serve.admit_request",
+                                        uid=int(req.uid)):
+                            self._admit(req, b)
                     except Exception as e:
                         # quarantine the failure to THIS request (§14):
                         # unwind the half-built slot (releasing whatever
@@ -2086,11 +2109,12 @@ class ServingEngine:
         # misses fall through to the host tier (DESIGN.md §13).
         hits, keys, host_keys = [], [], []
         nb_full = (L_p - 1) // self.block_size
-        if self._kv_share and nb_full:
-            hits, keys, host_keys = mgr.lookup_prefix_tiered(
-                prompt, nb_full, tier=self.tier, shard=shard)
-        elif self.rec_prefix and nb_full:
-            keys = chain_hashes(prompt, self.block_size, nb_full)
+        with self._span("serve.prefix_lookup", uid=int(req.uid)):
+            if self._kv_share and nb_full:
+                hits, keys, host_keys = mgr.lookup_prefix_tiered(
+                    prompt, nb_full, tier=self.tier, shard=shard)
+            elif self.rec_prefix and nb_full:
+                keys = chain_hashes(prompt, self.block_size, nb_full)
 
         rec_rows, rec_bound = None, 0
         if self.rec_prefix and nb_full:
@@ -2119,11 +2143,12 @@ class ServingEngine:
         self.tables[b] = 0
         self.tables[b, :len(hits)] = hits
         self._tables_dev = None
-        pre = self._take_prefetched(req.uid, shard)
-        staged = self._stage_host_blocks(b, mgr, host_keys, len(hits),
-                                         prefetched=pre) \
-            if host_keys else 0
-        self._ensure_capacity(b, L_p)
+        with self._span("serve.alloc_blocks", uid=int(req.uid)):
+            pre = self._take_prefetched(req.uid, shard)
+            staged = self._stage_host_blocks(b, mgr, host_keys, len(hits),
+                                             prefetched=pre) \
+                if host_keys else 0
+            self._ensure_capacity(b, L_p)
 
         if self.rec_prefix and rec_bound > (len(hits) + staged
                                             if self.has_attn else nb_full):
@@ -2140,10 +2165,11 @@ class ServingEngine:
         req.prefix_hit_blocks = start_blocks
 
         # per-slot state
-        self.tokens = self.tokens.at[b].set(0).at[b, :L_p].set(
-            jnp.asarray(prompt, jnp.int32))
-        self.n = self.n.at[b].set(L_p)
-        self.cand = self.cand.at[b].set(0).at[b, 0].set(int(prompt[-1]))
+        with self._span("serve.slot_state", uid=int(req.uid)):
+            self.tokens = self.tokens.at[b].set(0).at[b, :L_p].set(
+                jnp.asarray(prompt, jnp.int32))
+            self.n = self.n.at[b].set(L_p)
+            self.cand = self.cand.at[b].set(0).at[b, 0].set(int(prompt[-1]))
         self.seq_ids[b] = req.seq_id
         self._seq_dev = None
         if _has_recurrent(self.cfg):
@@ -2170,25 +2196,27 @@ class ServingEngine:
                     if self.rec_prefix else [])
         if not seg_ends or seg_ends[-1] != L_p - 1:
             seg_ends.append(L_p - 1)
-        for end in seg_ends:
-            for C in prefill_chunks(end - start, self.prefill_chunk):
-                chunk = jnp.asarray(prompt[None, start:start + C], jnp.int32)
-                pf = self._prefill_fn(C)
-                pf_args = (self.params, self.paged, table_row, row, chunk,
-                           jnp.asarray([start], jnp.int32))
-                self._contract_check("prefill", pf, pf_args)
-                self.paged = pf(*pf_args)
-                start += C
-                req.prefill_calls += 1
-                self.metrics.prefill_calls += 1
-            if (self.rec_prefix and end > 0 and end == start
-                    and end % self.block_size == 0
-                    and end <= nb_full * self.block_size):
-                kb = end // self.block_size - 1
-                if not self.tier.has_rec(shard, keys[kb]):
-                    if self.tier.put_rec(shard, keys[kb],
-                                         self._collect_rec_row(b)):
-                        self.metrics.rec_snapshot_captures += 1
+        with self._span("serve.prefill_dispatch", uid=int(req.uid)):
+            for end in seg_ends:
+                for C in prefill_chunks(end - start, self.prefill_chunk):
+                    chunk = jnp.asarray(prompt[None, start:start + C],
+                                        jnp.int32)
+                    pf = self._prefill_fn(C)
+                    pf_args = (self.params, self.paged, table_row, row,
+                               chunk, jnp.asarray([start], jnp.int32))
+                    self._contract_check("prefill", pf, pf_args)
+                    self.paged = pf(*pf_args)
+                    start += C
+                    req.prefill_calls += 1
+                    self.metrics.prefill_calls += 1
+                if (self.rec_prefix and end > 0 and end == start
+                        and end % self.block_size == 0
+                        and end <= nb_full * self.block_size):
+                    kb = end // self.block_size - 1
+                    if not self.tier.has_rec(shard, keys[kb]):
+                        if self.tier.put_rec(shard, keys[kb],
+                                             self._collect_rec_row(b)):
+                            self.metrics.rec_snapshot_captures += 1
 
         # publish this prompt's freshly computed full blocks (host-staged
         # ones were registered as they merged)
@@ -2391,11 +2419,19 @@ class ServingEngine:
         adaptive :class:`RoundsPerSyncController` (or stays at
         ``rounds_per_sync`` when adaptivity is off and the backlog is
         staged). Returns True while there is (or may be) work left."""
-        self._poll_queue_deadlines()
-        self._reconcile_staging()
-        self._admit_pending()
-        self._stage_pending()
-        self._prefetch_queued()
+        self._step_no += 1
+        with self._span("serve.step"):
+            return self._step()
+
+    def _step(self) -> bool:
+        """The body of :meth:`step`, one span per phase: ``serve.admit``,
+        ``serve.round_dispatch``, ``serve.sync``, ``serve.harvest``."""
+        with self._span("serve.admit"):
+            self._poll_queue_deadlines()
+            self._reconcile_staging()
+            self._admit_pending()
+            self._stage_pending()
+            self._prefetch_queued()
 
         if not any(s is not None for s in self.slots):
             # _reconcile_staging unstages whenever a slot is free, so an
@@ -2420,39 +2456,53 @@ class ServingEngine:
                     else 1
         else:
             k = 1 if self.queue else self.rounds_per_sync
-        for b in range(self.B):
-            if self.slots[b] is not None:
-                try:
-                    self._ensure_capacity(b, int(self.target[b]) + W)
-                except MemoryError as e:
-                    # reservation guarantees this never fires organically;
-                    # an injected alloc fault fails ONLY this slot (§14)
-                    self._fail_slot(b, "capacity", str(e), retryable=True)
-        if not any(s is not None for s in self.slots):
-            return bool(self.queue) or self._staged_total() > 0
-        adopt = otok_dev = None
-        round_fn = self._round_loop_fn(W, k)
-        round_args = self._round_args()
-        self._contract_check(
-            "round" if self.staging_slots == 0 else "staged_round",
-            round_fn, round_args)
-        if self.staging_slots == 0:
-            (self.paged, self.tokens, self.n, self.cand, stats_dev) = \
-                round_fn(*round_args)
-        else:
-            # staged ABI: row state comes BACK as outputs (adoption mutates
-            # tables/seq/target/poison/plen in-loop) and becomes the new
-            # device cache; host mirrors for adopted rows are updated in
-            # the harvest walk below WITHOUT invalidating these caches
-            (self.paged, self._tables_dev, self.tokens, self.n, self.cand,
-             self._seq_dev, self._target_dev, self._poison_dev,
-             self._plen_dev, stats_dev, adopt_dev, otok_dev) = \
-                round_fn(*round_args)
-            adopt = np.asarray(adopt_dev)
-            self.metrics.staging_occupancy_hist.append(
-                staged_now / (self.topo.data_size * self.staging_slots))
+        with self._span("serve.round_dispatch"):
+            for b in range(self.B):
+                if self.slots[b] is not None:
+                    try:
+                        self._ensure_capacity(b, int(self.target[b]) + W)
+                    except MemoryError as e:
+                        # reservation guarantees this never fires
+                        # organically; an injected alloc fault fails ONLY
+                        # this slot (§14)
+                        self._fail_slot(b, "capacity", str(e),
+                                        retryable=True)
+            if not any(s is not None for s in self.slots):
+                return bool(self.queue) or self._staged_total() > 0
+            adopt_dev = otok_dev = None
+            round_fn = self._round_loop_fn(W, k)
+            round_args = self._round_args()
+            self._contract_check(
+                "round" if self.staging_slots == 0 else "staged_round",
+                round_fn, round_args)
+            if self.staging_slots == 0:
+                (self.paged, self.tokens, self.n, self.cand, stats_dev) = \
+                    round_fn(*round_args)
+            else:
+                # staged ABI: row state comes BACK as outputs (adoption
+                # mutates tables/seq/target/poison/plen in-loop) and
+                # becomes the new device cache; host mirrors for adopted
+                # rows are updated in the harvest walk below WITHOUT
+                # invalidating these caches
+                (self.paged, self._tables_dev, self.tokens, self.n,
+                 self.cand, self._seq_dev, self._target_dev,
+                 self._poison_dev, self._plen_dev, stats_dev, adopt_dev,
+                 otok_dev) = round_fn(*round_args)
+                self.metrics.staging_occupancy_hist.append(
+                    staged_now / (self.topo.data_size * self.staging_slots))
         # THE host sync: one small packed int32 pull per loop
-        stats = np.asarray(stats_dev)
+        with self._span("serve.sync"):
+            adopt = None if adopt_dev is None else np.asarray(adopt_dev)
+            stats = np.asarray(stats_dev)
+        with self._span("serve.harvest"):
+            self._harvest(stats, adopt, otok_dev, W, backlog_now)
+        return True
+
+    def _harvest(self, stats: np.ndarray, adopt: Optional[np.ndarray],
+                 otok_dev, W: int, backlog_now: int) -> None:
+        """Everything a step does after its sync: credit adoptions and
+        rounds, retune W (and k), quarantine bad rows, finish or bound the
+        rest, and checkpoint a durable engine."""
         accepted, rounds_active, n_host = stats[:, 0], stats[:, 1], stats[:, 2]
         bad = stats[:, 4]                      # §14 quarantine health bits
         rounds_exec = int(stats[:, 3].max())   # critical path across shards
@@ -2530,7 +2580,6 @@ class ServingEngine:
             self.journal.sync()
             self._checkpoint(now)
             kill_point("post_sync")
-        return True
 
     def run(self, max_rounds: int = 10_000) -> list[Request]:
         """Drain the queue; returns completed Requests with stats.

@@ -1,15 +1,28 @@
-"""Serving telemetry: per-request and engine-level counters as plain dicts.
+"""Serving telemetry: per-request and engine-level counters as plain dicts,
+and a bounded log of host spans.
 
 No external metrics dependency — everything exports to ``dict`` so callers
 can feed dashboards, benchmark tables, or test assertions directly. The
 engine updates these from values it already syncs to host each round, so
 telemetry adds no extra device round-trips.
+
+Spans (:class:`SpanLog`) time the phases of the engine's host work on the
+``time.monotonic`` clock of ``Request.submit_time``/``admit_time``. Each
+span is also a ``jax.profiler.TraceAnnotation``, so a profile shows it on
+the host plane, on the same timeline as the device's operations.
 """
 from __future__ import annotations
 
+import time
+from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass, field
+from typing import NamedTuple, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+SPAN_LOG_SIZE = 2 ** 16             # records kept; older ones are dropped
 
 
 def percentile(values, p: float) -> float:
@@ -118,11 +131,6 @@ class EngineMetrics:
         self.tokens_accepted_hist.append(int(accepted))
         self.tokens_generated += int(accepted)
 
-    def observe_round(self, window: int, active: int, batch: int,
-                      accepted: int):
-        """Host-driven compatibility shim: a single round = a loop of 1."""
-        self.observe_loop(window, 1, active, batch, accepted)
-
     def observe_finish(self, req):
         self.requests_finished += 1
         self.request_latencies.append(req.latency)
@@ -225,3 +233,55 @@ class EngineMetrics:
             # bytes_resident/h2d_staged/h2d_overlap_frac, ...)
             out.update(host_stats)
         return out
+
+
+class Span(NamedTuple):
+    """One closed span of host time (``time.monotonic`` seconds)."""
+    name: str
+    t0: float
+    t1: float
+    step: Optional[int]         # the recording engine's step number
+    uid: Optional[int]          # request id, on request-scoped spans
+    engine: Optional[int]       # small integer naming the recording engine
+
+
+class SpanLog:
+    """A bounded log of host spans: the last ``maxlen`` closed spans, in
+    the order they closed (children before their parent).
+
+    Recording is always on: with the profiler off a span costs a few
+    microseconds of host time (PERF.md, "Spans and scopes")."""
+
+    def __init__(self, maxlen: int = SPAN_LOG_SIZE):
+        self.records: deque[Span] = deque(maxlen=maxlen)
+
+    @contextmanager
+    def span(self, name: str, *, step: Optional[int] = None,
+             uid: Optional[int] = None, engine: Optional[int] = None):
+        """``with log.span("serve.admit", step=s): ...`` records the
+        block's host time under ``name`` (also when it raises), inside a
+        ``TraceAnnotation`` of the same name carrying the ids."""
+        ids = {k: v for k, v in (("step", step), ("uid", uid),
+                                 ("engine", engine)) if v is not None}
+        with TraceAnnotation(name, **ids):
+            t0 = time.monotonic()
+            try:
+                yield
+            finally:
+                self.records.append(
+                    Span(name, t0, time.monotonic(), step, uid, engine))
+
+    def spans(self, t0: float, t1: float) -> list[Span]:
+        """The kept spans that start at or after ``t0`` and end at or
+        before ``t1``, in the order they closed."""
+        return [s for s in self.records if s.t0 >= t0 and s.t1 <= t1]
+
+
+_DEFAULT_LOG = SpanLog()
+
+
+def default_span_log() -> SpanLog:
+    """The process's span log, shared by every engine (as ``logging``'s
+    root logger is): readers reach the spans after the engine that
+    recorded them is gone."""
+    return _DEFAULT_LOG
