@@ -8,10 +8,16 @@ The load-bearing invariants:
   partially filled tail blocks, and W in {1, 4, 16} — on the attention
   output AND bitwise on the committed pools (excluding the reserved sink
   block 0, whose contents are garbage by design);
-* with matching tile sizes the fused kernel is BITWISE identical to the
-  dense ``decode_attention_kernel`` run over the post-write gathered view —
-  the same online-softmax op sequence, only the addressing (and the fused
-  commit) differs;
+* the same with several pages per compute block (``ppb``): lengths ending
+  inside, at the start and at the end of a block, window spans straddling
+  two blocks, a 64-wide (prefill) window over 5 pages, rows far shorter
+  than the table, shared prefix pages, a sliding window that skips whole
+  blocks, and the latent variant;
+* with the dense tile equal to the compute block (``ppb * bs``, as
+  ``pages_per_block`` picks it) the fused kernel is BITWISE identical to
+  the dense ``decode_attention_kernel`` run over the post-write gathered
+  view — the same online-softmax op sequence, only the addressing (and the
+  fused commit) differs;
 * the standalone aliased writeback (``paged_window_write``) is bitwise
   identical to the reference scatter, including inactive-row sink routing;
 * block tables with shared prefix blocks (prefix-cache hits) read the same
@@ -25,6 +31,9 @@ import pytest
 
 from repro.kernels.decode_attention.kernel import decode_attention_kernel
 from repro.kernels.decode_attention.ops import decode_attention
+from repro.kernels.paged_attention.kernel import (pages_per_block,
+                                                  paged_decode_kernel,
+                                                  paged_latent_kernel)
 from repro.kernels.paged_attention.ops import (paged_attention,
                                                paged_latent_attention,
                                                paged_window_write)
@@ -91,11 +100,15 @@ def test_fused_kernel_matches_ref_and_dense(bs, W):
 
 
 def test_fused_kernel_bitwise_vs_dense_kernel():
-    """Same tile size -> identical online-softmax op sequence: the fused
+    """Same tile width -> identical online-softmax op sequence: the fused
     paged kernel must reproduce the dense flash-decode kernel (run over the
-    post-write gathered view) bit-for-bit."""
-    B, W, H, KV, d, bs, nb = 2, 8, 4, 2, 32, 32, 4
+    post-write gathered view, ``block_k`` = the compute block's keys)
+    bit-for-bit, over several blocks and a ragged last one."""
+    B, W, H, KV, d, bs, nb = 2, 8, 4, 2, 32, 16, 80
     P = 1 + B * nb
+    ppb = pages_per_block(nb=nb, bs=bs, KV=KV, widths=(d, d), R=H // KV * W,
+                          dv=d, W=W, itemsize=4)
+    assert 1 < ppb < nb and nb % ppb                  # ragged last block
     key = jax.random.PRNGKey(7)
     kq, kp, kl, kn = jax.random.split(key, 4)
     q = jax.random.normal(kq, (B, W, H, d))
@@ -112,9 +125,78 @@ def test_fused_kernel_bitwise_vs_dense_kernel():
     kf = kd.transpose(0, 2, 1, 3).reshape(B * H, nb * bs, d)
     vf = vd.transpose(0, 2, 1, 3).reshape(B * H, nb * bs, d)
     dense = decode_attention_kernel(qf, kf, vf, jnp.repeat(lengths, H),
-                                    block_k=bs, interpret=True)
+                                    block_k=ppb * bs, interpret=True)
     dense = dense.reshape(B, H, W, d).transpose(0, 2, 1, 3)
     np.testing.assert_array_equal(np.asarray(paged), np.asarray(dense))
+
+
+def _grouped_kernel(q, k_pool, v_pool, k_new, v_new, tables, lengths, *,
+                    ppb, window=0):
+    """``paged_attention``'s kernel path at a chosen ``ppb``."""
+    B, W, H, d = q.shape
+    KV = k_pool.shape[2]
+    G = H // KV
+    qg = (q.reshape(B, W, KV, G, d).transpose(0, 2, 3, 1, 4)
+          .reshape(B, KV, G * W, d))
+    out, kp, vp = paged_decode_kernel(qg, k_pool, v_pool, k_new, v_new,
+                                      tables, lengths, W=W, ppb=ppb,
+                                      window=window, interpret=True)
+    out = (out.reshape(B, KV, G, W, d).transpose(0, 3, 1, 2, 4)
+           .reshape(B, W, H, d))
+    return out, kp, vp
+
+
+# bs 16; T = ppb * 16 keys per compute block. ``used`` keeps only the pages
+# a row uses in its table (the rest point at the sink, as in the engine).
+MULTI_PAGE_CASES = {
+    "ends-inside-block": dict(W=4, nb=12, ppb=4, lengths=[37, 100]),
+    "ends-at-block-start": dict(W=4, nb=12, ppb=4, lengths=[61, 125]),
+    "ends-at-block-end": dict(W=4, nb=12, ppb=4, lengths=[60, 124]),
+    "span-straddles-blocks": dict(W=8, nb=12, ppb=4, lengths=[60, 123]),
+    "prefill-w64-five-pages": dict(W=64, nb=12, ppb=4, lengths=[47]),
+    "rows-far-below-nb": dict(W=4, nb=40, ppb=4, lengths=[5, 30],
+                              used=True),
+    "shared-prefix": dict(W=4, nb=8, ppb=2, lengths=[40, 71], shared=2),
+    "sliding-window-skips-blocks": dict(W=4, nb=12, ppb=2, lengths=[150, 70],
+                                        window=24),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MULTI_PAGE_CASES))
+def test_multi_page_blocks_match_ref(case):
+    c = MULTI_PAGE_CASES[case]
+    W, nb, ppb, window = c["W"], c["nb"], c["ppb"], c.get("window", 0)
+    shared = c.get("shared", 0)
+    lengths = jnp.asarray(c["lengths"], jnp.int32)
+    B, H, KV, d, bs = len(c["lengths"]), 4, 2, 32, 16
+    P = 1 + shared + B * (nb - shared)
+    key = jax.random.PRNGKey(sum(map(ord, case)))
+    kq, kp, kn = jax.random.split(key, 3)
+    q = jax.random.normal(kq, (B, W, H, d))
+    k_pool, v_pool, tables = _pool_and_tables(kp, P, bs, nb, KV, d, B,
+                                              shared_prefix=shared)
+    if c.get("used"):
+        used = (lengths[:, None] + W - 1) // bs >= jnp.arange(nb)[None]
+        tables = jnp.where(used, tables, 0)
+        k_pool = k_pool.at[0].set(1e9)                # the sink never counts
+        v_pool = v_pool.at[0].set(-1e9)
+    k_new, v_new = _window_kv(kn, B, W, KV, d)
+
+    got, kp2, vp2 = _grouped_kernel(q, k_pool, v_pool, k_new, v_new, tables,
+                                    lengths, ppb=ppb, window=window)
+    rk = write_window_paged(k_pool, k_new, tables, lengths)
+    rv = write_window_paged(v_pool, v_new, tables, lengths)
+    np.testing.assert_array_equal(np.asarray(kp2)[1:], np.asarray(rk)[1:])
+    np.testing.assert_array_equal(np.asarray(vp2)[1:], np.asarray(rv)[1:])
+    want = paged_attention_ref(q, rk, rv, tables, lengths, window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    if shared:                                   # prefix pages only read
+        ids = np.asarray(tables[0, :shared])
+        np.testing.assert_array_equal(np.asarray(kp2)[ids],
+                                      np.asarray(k_pool)[ids])
+        np.testing.assert_array_equal(np.asarray(vp2)[ids],
+                                      np.asarray(v_pool)[ids])
 
 
 @pytest.mark.parametrize("window", [0, 24])
@@ -191,11 +273,17 @@ def test_sink_tail_blocks_never_contribute():
     np.testing.assert_array_equal(np.asarray(base), np.asarray(got))
 
 
-@pytest.mark.parametrize("W", [1, 4])
-def test_fused_latent_kernel_matches_ref(W):
-    B, H, r, dr, bs, nb = 2, 4, 24, 16, 16, 3
+# (W, nb, ppb, lengths): ppb None is what paged_latent_attention picks (one
+# block over the whole table here); the explicit ppb cases put row 1's
+# window across the boundary of two compute blocks
+@pytest.mark.parametrize("W,nb,ppb,lengths", [
+    (1, 3, None, None), (4, 3, None, None),
+    (4, 6, 1, [20, 30]), (4, 6, 2, [20, 62])],
+    ids=["1", "4", "4-ppb1-straddle", "4-ppb2-straddle"])
+def test_fused_latent_kernel_matches_ref(W, nb, ppb, lengths):
+    B, H, r, dr, bs = 2, 4, 24, 16, 16
     P = 1 + B * nb
-    key = jax.random.PRNGKey(W)
+    key = jax.random.PRNGKey(W if ppb is None else 40 + ppb)
     k1, k2, k3, k4, kl, kn = jax.random.split(key, 6)
     q_lat = jax.random.normal(k1, (B, W, H, r))
     q_rope = jax.random.normal(k2, (B, W, H, dr))
@@ -205,11 +293,22 @@ def test_fused_latent_kernel_matches_ref(W):
     kr_new = jax.random.normal(jax.random.fold_in(kn, 1), (B, W, dr))
     ids = np.arange(1, P).reshape(B, nb)
     tables = jnp.asarray(ids, jnp.int32)
-    lengths = jax.random.randint(kl, (B,), 1, nb * bs - W)
+    lengths = (jax.random.randint(kl, (B,), 1, nb * bs - W) if lengths is None
+               else jnp.asarray(lengths, jnp.int32))
     scale = 1.0 / np.sqrt(r + dr)
-    got, c2, kr2 = paged_latent_attention(q_lat, q_rope, c_pool, kr_pool,
-                                          c_new, kr_new, tables, lengths,
-                                          scale, interpret=True)
+    if ppb is None:
+        got, c2, kr2 = paged_latent_attention(q_lat, q_rope, c_pool, kr_pool,
+                                              c_new, kr_new, tables, lengths,
+                                              scale, interpret=True)
+    else:
+        out, c2, kr2 = paged_latent_kernel(
+            q_lat.transpose(0, 2, 1, 3).reshape(B, 1, H * W, r),
+            q_rope.transpose(0, 2, 1, 3).reshape(B, 1, H * W, dr),
+            c_pool[:, :, None], kr_pool[:, :, None], c_new[:, :, None],
+            kr_new[:, :, None], tables, lengths, W=W, ppb=ppb, scale=scale,
+            interpret=True)
+        got = out.reshape(B, H, W, r).transpose(0, 2, 1, 3)
+        c2, kr2 = c2[:, :, 0], kr2[:, :, 0]
     rc = write_window_paged(c_pool, c_new, tables, lengths)
     rkr = write_window_paged(kr_pool, kr_new, tables, lengths)
     want = paged_latent_ref(q_lat, q_rope, rc, rkr, tables, lengths,
