@@ -2,7 +2,8 @@
 
 A configuration is a JSON file under ``bench/configs`` that keeps the keys of
 the model's public ``config.json``. This module turns it into the serving
-program's ``ModelConfig`` and draws the weights from the run's seed, on the
+program's ``ModelConfig``, refusing a file that states what the program
+cannot express, and draws the weights from the run's seed, on the
 device, in one jitted call, in the type they are served in.
 
 Weights are drawn by name and layer (``leaf(name, layer)``), so that the
@@ -12,6 +13,7 @@ device gives the same numbers.
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 import math
 from pathlib import Path
@@ -45,21 +47,77 @@ def jax_seed(seed: int, stream: str) -> int:
     return int(ss.generate_state(1, np.uint32)[0] >> 1)
 
 
-def model_config(cfg: dict):
-    """The serving program's ``ModelConfig`` for a configuration file."""
+# Every key of a configuration file is in exactly one of three lists
+# (``key_lists``), and ``model_config`` refuses a key in none of them, so a
+# configuration is served as its file states it or not at all.
+#
+# carried: the value goes to the ``ModelConfig`` field named here (with the
+# conversion given), where the program declares that field.
+CARRIED = {
+    "num_hidden_layers": ("n_layers", int), "hidden_size": ("d_model", int),
+    "intermediate_size": ("d_ff", int), "vocab_size": ("vocab", int),
+    "num_attention_heads": ("n_heads", int),
+    "num_key_value_heads": ("n_kv_heads", int),
+    "head_dim": ("head_dim", int), "qk_norm": ("qk_norm", bool),
+    "rope_theta": ("rope_theta", float),
+    "tie_word_embeddings": ("tie_embeddings", bool),
+    "torch_dtype": ("dtype", str), "rms_norm_eps": ("norm_eps", float)}
+CARRIED_DEFAULTS = {"qk_norm": False}     # the only carried key a file may omit
+# held: the program fixes the value, so the file may state that value only
+# (or omit the key). A carried key whose field the program does not declare
+# is held at the value the program uses: the RMSNorm eps of
+# ``repro.nn.core.RMSNorm``.
+HELD = {"rms_norm_eps": 1e-6, "hidden_act": "silu", "attention_bias": False,
+        "mlp_bias": False, "rope_scaling": None, "sliding_window": None,
+        "use_sliding_window": False}
+# descriptive: labels and settings that cannot change the served forward
+# pass (dropout and initialisation act in training only; token ids are the
+# tokenizer's). ``max_position_embeddings`` is checked against ``max_len``.
+DESCRIPTIVE = frozenset({
+    "name", "source", "reference", "reduced", "assumed", "deployment", "check",
+    "architectures", "model_type", "max_position_embeddings",
+    "transformers_version", "bos_token_id", "eos_token_id", "pad_token_id",
+    "initializer_range", "attention_dropout", "use_cache"})
+
+
+def key_lists(config_class) -> tuple[dict, dict, frozenset]:
+    """The carried, held and descriptive keys for the program's config
+    class: ``({key: (field, conversion)}, {key: value}, keys)``."""
+    fields = {f.name for f in dataclasses.fields(config_class)}
+    carried = {k: v for k, v in CARRIED.items() if v[0] in fields}
+    held = {k: v for k, v in HELD.items() if k not in carried}
+    return carried, held, DESCRIPTIVE
+
+
+def model_config(cfg: dict, max_len: int | None = None):
+    """The serving program's ``ModelConfig`` for a configuration file, built
+    from the file's carried keys. Raises ``ValueError``, naming the key, for
+    a key in none of the three lists, a held key at another value than the
+    program's, a missing carried key, or a ``max_position_embeddings``
+    below ``max_len`` (the engine's, where given)."""
     from repro.models.transformer import ModelConfig
-    if cfg.get("hidden_act", "silu") != "silu":
-        raise ValueError(f"{cfg['name']}: only SwiGLU MLPs are modelled")
-    return ModelConfig(
-        name=cfg["name"], arch_type="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        layer_block=(("attn", "dense"),), qk_norm=bool(cfg.get("qk_norm")),
-        rope_theta=float(cfg["rope_theta"]), mlp_kind="swiglu",
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        dtype=cfg["torch_dtype"], source=cfg["source"])
+    carried, held, descriptive = key_lists(ModelConfig)
+    name = cfg.get("name")
+    for k in sorted(cfg):
+        if k not in carried and k not in held and k not in descriptive:
+            raise ValueError(f"{name}: key {k!r} is in none of bench/model.py's"
+                             " lists (carried, held, descriptive)")
+        if k in held and cfg[k] != held[k]:
+            raise ValueError(f"{name}: {k} is {cfg[k]!r}; the program fixes it"
+                             f" at {held[k]!r} and has no field to carry "
+                             "another value")
+    for k in sorted(set(CARRIED) - set(CARRIED_DEFAULTS)):
+        if k not in cfg:
+            raise ValueError(f"{name}: key {k!r} is missing")
+    limit = cfg.get("max_position_embeddings")
+    if max_len is not None and limit is not None and max_len > limit:
+        raise ValueError(f"{name}: max_len {max_len} exceeds "
+                         f"max_position_embeddings {limit}")
+    fields = {f: conv(cfg.get(k, CARRIED_DEFAULTS.get(k)))
+              for k, (f, conv) in carried.items()}
+    return ModelConfig(name=cfg["name"], arch_type="dense",
+                       layer_block=(("attn", "dense"),), mlp_kind="swiglu",
+                       source=cfg["source"], **fields)
 
 
 def leaf_shapes(cfg: dict) -> dict:

@@ -9,7 +9,9 @@ The cell (``BENCHMARK.json`` ``workloads``) names a configuration
 the end-to-end ones with ``--trace 0``, the per-layer ones with ``--trace 1``
 (a run of its own, traced by the profiler over the whole window).
 
-One run: draw the weights from the seed on the device; build the engine;
+One run: build the program's ``ModelConfig`` from the configuration file,
+which refuses a key the program cannot take as stated (``bench/model.py``);
+draw the weights from the seed on the device; build the engine;
 compile every program the mix can use; prefill the mix's documents; serve
 ``warmup_s`` seconds of the traffic; open the window and serve ``--seconds``
 more, stamping every token; close the window, read the device's peak memory,
@@ -183,6 +185,9 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
     cell = next(w for w in bench["workloads"] if w["name"] == cell_name)
     cfg = cfg or model.load_config(cell["config"])
     mix = mix or load_mix(cell["traffic"])
+    ekw = mix["engine"]
+    # refuses a configuration the program cannot express, before any weight
+    mcfg = model.model_config(cfg, ekw["max_len"])
     dev = jax.devices()[0]
     kind = dev.device_kind
     peaks = roofline.peaks_for(kind) if dev.platform == "tpu" else None
@@ -200,9 +205,7 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
     model.check_layout(cfg, params)
     say(f"setup: weights {clock() - t:.3f} s")
     eps_fn = model.make_eps_fn(cfg["vocab_size"])
-    ekw = mix["engine"]
-    eng = ServingEngine(model.model_config(cfg), params, eps_fn=eps_fn,
-                        **ekw)
+    eng = ServingEngine(mcfg, params, eps_fn=eps_fn, **ekw)
     traffic = Traffic(mix, seed, cfg["vocab_size"])
     t = clock()
     warm_up(eng, traffic)

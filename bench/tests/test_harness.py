@@ -61,9 +61,12 @@ def test_altered_token_is_not_correct(monkeypatch):
 
     real = spec_decode.reparam_argmax
 
-    def altered(logits, eps):           # every token id divisible by 13
-        out = real(logits, eps)         # is replaced by its neighbour
-        return jnp.where(out % 13 == 0, (out + 1) % logits.shape[-1], out)
+    # every token id divisible by 3 is replaced by its neighbour: a third of
+    # the tokens, so that the 32 or more checked all come out clean with a
+    # chance of about (2/3)**32, 2e-6, whichever requests finish in time
+    def altered(logits, eps):
+        out = real(logits, eps)
+        return jnp.where(out % 3 == 0, (out + 1) % logits.shape[-1], out)
 
     monkeypatch.setattr(spec_decode, "reparam_argmax", altered)
     out = small_run()
