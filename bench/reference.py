@@ -120,21 +120,22 @@ class Reference:
     def __init__(self, cfg: dict, seed: int):
         self.cfg = cfg
         self.key = model.weights_key(seed)
+        fam = model.family(cfg)
         dt = jnp.dtype(cfg["torch_dtype"])
         f32 = jnp.float32
 
         def weights(key, layer):
             # the served dtype first, so the values are the served ones
             return {n: x.astype(f32) for n, x in
-                    model.layer_leaves(key, cfg, layer, dt).items()}
+                    fam.layer_leaves(key, cfg, layer, dt).items()}
 
         def top(key):
-            out = {"embed": model.leaf(key, cfg, "embed", 0, dt).astype(f32),
-                   "final_norm": model.leaf(key, cfg, "final_norm", 0,
-                                            dt).astype(f32)}
+            out = {"embed": fam.leaf(key, cfg, "embed", 0, dt).astype(f32),
+                   "final_norm": fam.leaf(key, cfg, "final_norm", 0,
+                                          dt).astype(f32)}
             out["head"] = (out["embed"].T if cfg["tie_word_embeddings"]
-                           else model.leaf(key, cfg, "head", 0,
-                                           dt).astype(f32))
+                           else fam.leaf(key, cfg, "head", 0,
+                                         dt).astype(f32))
             return out
 
         self._weights = jax.jit(weights)
@@ -146,7 +147,9 @@ class Reference:
         """Final-normed hidden states ``(n, S, d)`` of ``tokens (n, S)``,
         ``S`` a multiple of ``Q_BLOCK``. Each layer's weights are drawn
         once; the sequences go through it one at a time, so that every run
-        of a cell reuses one compiled layer."""
+        of a cell reuses one compiled layer. A layer's weights are let go
+        before the next layer's are drawn: one layer's are held at a
+        time."""
         top = self._top(self.key)
         hs = [top["embed"][jnp.asarray(t)[None]] for t in np.asarray(tokens)]
         if control:
@@ -154,6 +157,7 @@ class Reference:
         for layer in range(self.cfg["num_hidden_layers"]):
             w = self._weights(self.key, layer)
             hs = [self._layer(w, h, control) for h in hs]
+            del w
         h = jnp.concatenate(hs)
         return _rms(h, top["final_norm"], self.cfg["rms_norm_eps"]), top
 

@@ -9,14 +9,15 @@ The cell (``BENCHMARK.json`` ``workloads``) names a configuration
 the end-to-end ones with ``--trace 0``, the per-layer ones with ``--trace 1``
 (a run of its own, traced by the profiler over the whole window).
 
-One run: build the program's ``ModelConfig`` from the configuration file,
-which refuses a key the program cannot take as stated (``bench/model.py``);
-draw the weights from the seed on the device; build the engine;
-compile every program the mix can use; prefill the mix's documents; serve
-``warmup_s`` seconds of the traffic; open the window and serve ``--seconds``
-more, stamping every token; close the window, read the device's peak memory,
-free the engine, and compare a seeded sample of the finished requests with
-the plain float32 reference (``bench/reference.py``). The last line of
+One run: build the program's ``ModelConfig`` from the configuration file
+by its family (``bench/families/<family>.py``), which refuses a key the
+program cannot take as stated; draw the weights from the seed on the
+device; build the engine; compile every program the mix can use; prefill
+the mix's documents; serve ``warmup_s`` seconds of the traffic; open the
+window and serve ``--seconds`` more, stamping every token; close the
+window, read the device's peak memory, free the engine, and compare a
+seeded sample of the finished requests with the plain float32 reference
+the configuration names (``bench/reference.py``). The last line of
 standard output is one JSON object; the numbers compared, each beside its
 limit, are the last lines of standard error and the result's last key.
 
@@ -42,7 +43,6 @@ T_PROCESS = time.monotonic() - _process_age()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -56,6 +56,8 @@ for p in (str(ROOT / "src"), str(ROOT)):
         sys.path.insert(0, p)
 
 import numpy as np  # noqa: E402
+
+from bench.model import load_module  # noqa: E402
 
 EXIT_NO_CHIP = 3
 DOC_UID = 1_000_000_000           # request ids of the document fill
@@ -104,13 +106,6 @@ class Run:
 
     def note(self, line: str):
         self.notes.append(line)
-
-
-def load_module(path: Path, name: str):
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def load_reader(name: str):
@@ -187,7 +182,8 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
     mix = mix or load_mix(cell["traffic"])
     ekw = mix["engine"]
     # refuses a configuration the program cannot express, before any weight
-    mcfg = model.model_config(cfg, ekw["max_len"])
+    fam = model.family(cfg)
+    mcfg = fam.model_config(cfg, ekw["max_len"])
     dev = jax.devices()[0]
     kind = dev.device_kind
     peaks = roofline.peaks_for(kind) if dev.platform == "tpu" else None
@@ -200,9 +196,9 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
 
     # -- set-up ------------------------------------------------------------
     t = clock()
-    params = model.program_params(cfg, seed)
+    params = fam.program_params(cfg, seed)
     jax.block_until_ready(params)
-    model.check_layout(cfg, params)
+    fam.check_layout(cfg, params)
     say(f"setup: weights {clock() - t:.3f} s")
     eps_fn = model.make_eps_fn(cfg["vocab_size"])
     eng = ServingEngine(mcfg, params, eps_fn=eps_fn, **ekw)

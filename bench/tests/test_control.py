@@ -17,8 +17,9 @@ def test_fp8_control_fails_where_bf16_program_passes():
                vocab_size=2048, num_attention_heads=4, num_key_value_heads=2,
                head_dim=64, torch_dtype="bfloat16")
     seed = 3
-    eng = ServingEngine(model.model_config(cfg),
-                        model.program_params(cfg, seed), batch=4,
+    fam = model.family(cfg)
+    eng = ServingEngine(fam.model_config(cfg),
+                        fam.program_params(cfg, seed), batch=4,
                         window_max=4, max_len=256, block_size=16,
                         prefill_chunk=32,
                         eps_fn=model.make_eps_fn(cfg["vocab_size"]))

@@ -26,9 +26,10 @@ def small(variant):
 
 def engine(cfg, seed, **kw):
     from repro.serving import ServingEngine
-    params = model.program_params(cfg, seed)
-    model.check_layout(cfg, params)
-    return ServingEngine(model.model_config(cfg), params, batch=2,
+    fam = model.family(cfg)
+    params = fam.program_params(cfg, seed)
+    fam.check_layout(cfg, params)
+    return ServingEngine(fam.model_config(cfg), params, batch=2,
                          window_max=4, max_len=128, block_size=8,
                          prefill_chunk=16,
                          eps_fn=model.make_eps_fn(cfg["vocab_size"]),
